@@ -1,0 +1,6 @@
+"""Share of the qgamp_step kernel's roofline: its least time at the chip's peaks
+over its summed device time in the traced window (device trace)."""
+
+
+def read(ctx):
+    return ctx.roofline("qgamp_step")
