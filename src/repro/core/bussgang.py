@@ -29,6 +29,7 @@ dequantized codes (see runtime/collectives.py).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.codebook import as_codebook
@@ -59,11 +60,13 @@ def aggregate_codes(
     rhos: jnp.ndarray,  # (K,)
     quantizer,  # Codebook or legacy LloydMaxQuantizer
 ) -> jnp.ndarray:
-    """q_tilde (nb, M): the Bussgang-weighted aggregate of eq. 23."""
+    """q_tilde (nb, M): the Bussgang-weighted aggregate of eq. 23 (under
+    the ``dequantize`` scope)."""
     cb = as_codebook(quantizer)
-    deq = cb.decode(codes)  # (K, nb, M)
-    w = bussgang_weight(rhos[:, None], alphas, cb)  # (K, nb)
-    return jnp.sum(w[..., None] * deq, axis=0)
+    with jax.named_scope("dequantize"):
+        deq = cb.decode(codes)  # (K, nb, M)
+        w = bussgang_weight(rhos[:, None], alphas, cb)  # (K, nb)
+        return jnp.sum(w[..., None] * deq, axis=0)
 
 
 def aggregate_packed(
@@ -78,11 +81,13 @@ def aggregate_packed(
     groups (compression.decode_packed) so the (K, nb, M) uint8 code view
     never materializes at the PS boundary; vq unpacks indices and reads
     centroids.  The index width is the codebook's own ``bits``.
-    Numerically identical to ``aggregate_codes(unpack_codes(words), ...)``."""
+    Numerically identical to ``aggregate_codes(unpack_codes(words), ...)``
+    (and, like it, under the ``dequantize`` scope)."""
     cb = as_codebook(quantizer)
-    deq = cb.decode_packed(words, m)  # (K, nb, M)
-    w = bussgang_weight(rhos[:, None], alphas, cb)  # (K, nb)
-    return jnp.sum(w[..., None] * deq, axis=0)
+    with jax.named_scope("dequantize"):
+        deq = cb.decode_packed(words, m)  # (K, nb, M)
+        w = bussgang_weight(rhos[:, None], alphas, cb)  # (K, nb)
+        return jnp.sum(w[..., None] * deq, axis=0)
 
 
 def effective_noise_var(

@@ -156,36 +156,39 @@ def chunked_rows(
     (sensing matrix, threshold tables) is replicated.
 
     ``chunk <= 0`` or a chunk covering all rows degrades to one direct call.
+    Either way the solve, its padding and its scan run under the ``gamp``
+    scope (obs/trace.py).
     """
-    rows = inputs[0].shape[0]
-    if chunk <= 0 or (chunk >= rows and mesh is None):
-        return solve(*inputs)
-    nch = -(-rows // chunk)
-    if mesh is not None:
-        ndev = mesh.shape[axis_name]
-        nch = -(-nch // ndev) * ndev
-    padded = _pad_rows_zero(inputs, rows, nch * chunk)
-    chunked = tuple(x.reshape((nch, chunk) + x.shape[1:]) for x in padded)
+    with jax.named_scope("gamp"):
+        rows = inputs[0].shape[0]
+        if chunk <= 0 or (chunk >= rows and mesh is None):
+            return solve(*inputs)
+        nch = -(-rows // chunk)
+        if mesh is not None:
+            ndev = mesh.shape[axis_name]
+            nch = -(-nch // ndev) * ndev
+        padded = _pad_rows_zero(inputs, rows, nch * chunk)
+        chunked = tuple(x.reshape((nch, chunk) + x.shape[1:]) for x in padded)
 
-    def scan_chunks(*xs):
-        _, out = jax.lax.scan(lambda _, c: (None, solve(*c)), None, xs)
-        return out
+        def scan_chunks(*xs):
+            _, out = jax.lax.scan(lambda _, c: (None, solve(*c)), None, xs)
+            return out
 
-    if mesh is None:
-        out = scan_chunks(*chunked)
-    else:
-        from jax.sharding import PartitionSpec as P
+        if mesh is None:
+            out = scan_chunks(*chunked)
+        else:
+            from jax.sharding import PartitionSpec as P
 
-        spec = P(axis_name)
-        out = jax.shard_map(
-            scan_chunks,
-            mesh=mesh,
-            in_specs=(spec,) * len(chunked),
-            out_specs=spec,
-            axis_names={axis_name},
-            check_vma=False,
-        )(*chunked)
-    return out.reshape(nch * chunk, out_width)[:rows]
+            spec = P(axis_name)
+            out = jax.shard_map(
+                scan_chunks,
+                mesh=mesh,
+                in_specs=(spec,) * len(chunked),
+                out_specs=spec,
+                axis_names={axis_name},
+                check_vma=False,
+            )(*chunked)
+        return out.reshape(nch * chunk, out_width)[:rows]
 
 
 def ea_solve_flat(

@@ -58,7 +58,17 @@ from repro.core.reconstruction import (
     gamp_config_from,
 )
 from repro.obs import NULL_RECORDER
-from repro.obs.trace import SUB_PHASES, SpanCollector, span
+from repro.obs.trace import (
+    APPLY,
+    CLIENT,
+    DECODE,
+    SUB_PHASES,
+    UPLINK,
+    SpanCollector,
+    round_step,
+    scoped,
+    span,
+)
 from repro.fed.channel import (
     CHANNEL_FAMILIES,
     ChannelConfig,
@@ -344,12 +354,15 @@ class CohortEngine:
         self.sched_state = SchedulerState.init(self.clients)
         self.round = 0
         self.key = jax.random.PRNGKey(cohort.seed)
-        self._grads_jit = jax.jit(self._grad_blocks_fn)
-        self._encode_jit = jax.jit(self._encode_fn)  # loop-oracle unit
+        # Each program of the round runs under its layer's scope
+        # (obs/trace.py); the scopes leave the programs' names as they were.
+        self._grads_jit = jax.jit(scoped(self._grad_blocks_fn, CLIENT))
+        # loop-oracle unit
+        self._encode_jit = jax.jit(scoped(self._encode_fn, CLIENT, "encode"))
         # the cohort residual rows arrive as a fresh gather (residuals[jids])
         # consumed only by the encode, so the new residual writes in place
         self._encode_vmap_jit = jax.jit(
-            jax.vmap(self._encode_fn), donate_argnums=(1,)
+            scoped(jax.vmap(self._encode_fn), CLIENT, "encode"), donate_argnums=(1,)
         )
         if cohort.encode_stream:
             # Per-segment units of the streamed client pass: the batched
@@ -357,27 +370,28 @@ class CohortEngine:
             # view, the vmapped per-segment encode (top-S budget static so a
             # layout's per-segment s values each get their own graph), and
             # the running true-sum fold for nmse bookkeeping.
-            self._grads_tree_jit = jax.jit(self._grads_tree_fn)
+            self._grads_tree_jit = jax.jit(scoped(self._grads_tree_fn, CLIENT))
             self._seg_blocks_jit = jax.jit(
-                self.layout.segment_blocks_batched, static_argnums=(1,)
+                scoped(self.layout.segment_blocks_batched, CLIENT), static_argnums=(1,)
             )
             # segment residual rows are a fresh slice (residuals[:, rows]):
             # donated so each segment's new residual reuses that buffer
             self._encode_seg_jit = jax.jit(
-                jax.vmap(self._encode_segment_fn, in_axes=(0, 0, 0, None)),
+                scoped(jax.vmap(self._encode_segment_fn, in_axes=(0, 0, 0, None)),
+                       CLIENT, "encode"),
                 static_argnums=(3,),
                 donate_argnums=(1,),
             )
-            self._seg_true_sum_jit = jax.jit(
-                lambda rhos, blocks: jnp.einsum("k,kbn->bn", rhos, blocks)
-            )
-        self._ps_jit = jax.jit(self._ps_fn)
+            self._seg_true_sum_jit = jax.jit(scoped(
+                lambda rhos, blocks: jnp.einsum("k,kbn->bn", rhos, blocks), CLIENT
+            ))
+        self._ps_jit = jax.jit(scoped(self._ps_fn, DECODE))
         self._uplink_jit = jax.jit(
-            lambda key, c, nb: realize_uplink(self.chan, key, c, nb),
+            scoped(lambda key, c, nb: realize_uplink(self.chan, key, c, nb), UPLINK),
             static_argnums=(1, 2),
         )
         # per-round prep (effective rhos + per-client keys) in one dispatch
-        self._prep_jit = jax.jit(self._prep_fn)
+        self._prep_jit = jax.jit(scoped(self._prep_fn, CLIENT))
         if stream is not None:
             # One StreamingPS reused across rounds (owns the jitted folds).
             self._stream_ps = StreamingPS(
@@ -408,15 +422,18 @@ class CohortEngine:
         # blocks -> tree -> server update in one jitted apply (the per-round
         # fixed cost would otherwise be tens of eager dispatches and dominate
         # small cohorts).
-        self._apply_jit = jax.jit(
+        self._apply_jit = jax.jit(scoped(
             lambda ghat_blocks, params, sstate, step: server_update(
                 self.server,
                 blocks_to_tree(ghat_blocks, self.spec, self.nbar),
                 sstate,
                 params,
                 step,
-            )
-        )
+            ),
+            APPLY,
+        ))
+        # the cohort's new residual rows written back (a copy, not donated)
+        self._scatter_jit = jax.jit(scoped(_scatter_residuals, APPLY))
         if self._collect:
             # one fused reduction per round: update + param global l2 norms
             self._norms_jit = jax.jit(
@@ -733,7 +750,8 @@ class CohortEngine:
                     stats.update(gamp_health(ginfo))
             else:
                 m = self.fed_cfg.m
-                deq = self.codec.dequantize(codes)  # (C, nb, M)
+                with jax.named_scope("dequantize"):
+                    deq = self.codec.dequantize(codes)  # (C, nb, M)
                 w = bussgang.bussgang_weight(rhos_eff[:, None], alphas, q)  # (C, nb)
                 if fam.multiple_access:
                     # Over-the-air joint estimation: every client pre-scales
@@ -770,11 +788,12 @@ class CohortEngine:
                     nu_ch = jnp.sum(jnp.square(w) * nu_chan, axis=0)  # (nb,)
                 stats["nu_channel"] = jnp.mean(nu_ch)
                 energy = bussgang.signal_energy(alphas, rhos_eff, m, self.n)
-                ghat = em_gamp(
-                    y, nu_q + nu_ch, self.codec.a, self.gamp,
-                    init_var=energy, use_pallas=self.fed_cfg.use_kernels,
-                    with_info=self._collect,
-                )
+                with jax.named_scope("gamp"):
+                    ghat = em_gamp(
+                        y, nu_q + nu_ch, self.codec.a, self.gamp,
+                        init_var=energy, use_pallas=self.fed_cfg.use_kernels,
+                        with_info=self._collect,
+                    )
                 if self._collect:
                     ghat, ginfo = ghat
                     stats.update(gamp_health(ginfo))
@@ -850,16 +869,21 @@ class CohortEngine:
         returns the round's stats (python floats).  ``inspect``, when given,
         is called with the round's stacked cohort payloads and the decoded
         aggregate blocks (nb, N), before the server update applies them."""
-        if self.stream is not None:
-            return self._run_round_streaming(inspect)
+        with round_step(self.round):
+            if self.stream is not None:
+                return self._run_round_streaming(inspect)
+            return self._run_round_barrier(inspect)
+
+    def _run_round_barrier(self, inspect=None) -> Dict[str, float]:
         t = self.round
-        prev_sched = self.sched_state
-        ids, rho0, new_sched = select_cohort(
-            self.sched, prev_sched, t, self.data.counts
-        )
-        stale = self._staleness(prev_sched, ids, t) if self._collect else ()
-        kr = jax.random.fold_in(self.key, t)
-        k_chan, k_noise = jax.random.split(kr)
+        with span("select", self._spans):
+            prev_sched = self.sched_state
+            ids, rho0, new_sched = select_cohort(
+                self.sched, prev_sched, t, self.data.counts
+            )
+            stale = self._staleness(prev_sched, ids, t) if self._collect else ()
+            kr = jax.random.fold_in(self.key, t)
+            k_chan, k_noise = jax.random.split(kr)
         with span("uplink", self._spans):
             chan = self._uplink_jit(k_chan, len(ids), self.nb)
             if self._collect:
@@ -867,12 +891,15 @@ class CohortEngine:
         # Channel outage is a failed participation: un-stamp those clients so
         # the async staleness discount sees their true last *successful*
         # round (their residual carries the full gradient meanwhile).
-        dead = ids[np.asarray(chan.mask) == 0]
+        with span("sync", self._spans):
+            mask = np.asarray(chan.mask)
+        dead = ids[mask == 0]
         if len(dead):
             new_sched.last_round[dead] = prev_sched.last_round[dead]
         self.sched_state = new_sched
-        jids = jnp.asarray(ids)
-        rhos_eff, keys = self._prep_jit(jnp.asarray(rho0), chan.mask, jids, kr)
+        with span("select", self._spans):
+            jids = jnp.asarray(ids)
+            rhos_eff, keys = self._prep_jit(jnp.asarray(rho0), chan.mask, jids, kr)
 
         with span("client_pass", self._spans):
             batch = self.data.cohort_batch(t, ids)
@@ -892,7 +919,7 @@ class CohortEngine:
         del payloads  # wire + nmse reference: decoded, no longer needed
 
         with span("apply", self._spans):
-            self.residuals = self.residuals.at[jids].set(new_res)
+            self.residuals = self._scatter_jit(self.residuals, jids, new_res)
             del new_res
             self.params, self.server_state = self._apply_jit(
                 ghat_blocks, self.params, self.server_state, t
@@ -900,9 +927,10 @@ class CohortEngine:
             if self._collect:
                 jax.block_until_ready(self.params)
         self.round = t + 1
-        out = {k: float(v) for k, v in stats.items()}
+        with span("sync", self._spans):
+            out = {k: float(v) for k, v in stats.items()}
+            out["participating"] = float(jnp.sum(rhos_eff > 0))
         out["cohort"] = len(ids)
-        out["participating"] = float(jnp.sum(rhos_eff > 0))
         if self._collect:
             self._record_round(t, out, stale, ghat_blocks)
         return out
@@ -915,18 +943,20 @@ class CohortEngine:
         weight 0 (full residual carry) and un-stamped, exactly like channel
         outage."""
         t = self.round
-        prev_sched = self.sched_state
-        ids, rho0, new_sched = select_cohort(
-            self.sched, prev_sched, t, self.data.counts
-        )
-        stale = self._staleness(prev_sched, ids, t) if self._collect else ()
-        kr = jax.random.fold_in(self.key, t)
-        k_chan, k_noise = jax.random.split(kr)
+        with span("select", self._spans):
+            prev_sched = self.sched_state
+            ids, rho0, new_sched = select_cohort(
+                self.sched, prev_sched, t, self.data.counts
+            )
+            stale = self._staleness(prev_sched, ids, t) if self._collect else ()
+            kr = jax.random.fold_in(self.key, t)
+            k_chan, k_noise = jax.random.split(kr)
         with span("uplink", self._spans):
             chan = self._uplink_jit(k_chan, len(ids), self.nb)
             if self._collect:
                 jax.block_until_ready(chan)
-        mask = np.asarray(chan.mask)
+        with span("sync", self._spans):
+            mask = np.asarray(chan.mask)
         alive = (np.asarray(rho0) > 0) & (mask > 0)
         times = simulate_arrivals(self.stream, t, len(ids), alive)
         arrived = times <= self.stream.deadline
@@ -940,11 +970,12 @@ class CohortEngine:
         if len(dead):
             new_sched.last_round[dead] = prev_sched.last_round[dead]
         self.sched_state = new_sched
-        jids = jnp.asarray(ids)
-        jw = jnp.asarray(w_raw)
-        # _prep_fn's normalization of the raw weights is exactly the nmse
-        # reference weighting; the mask is already folded into w_raw.
-        rhos_eff, keys = self._prep_jit(jw, jnp.ones_like(jw), jids, kr)
+        with span("select", self._spans):
+            jids = jnp.asarray(ids)
+            jw = jnp.asarray(w_raw)
+            # _prep_fn's normalization of the raw weights is exactly the nmse
+            # reference weighting; the mask is already folded into w_raw.
+            rhos_eff, keys = self._prep_jit(jw, jnp.ones_like(jw), jids, kr)
 
         with span("client_pass", self._spans):
             batch = self.data.cohort_batch(t, ids)
@@ -979,7 +1010,7 @@ class CohortEngine:
             inspect(payloads, ghat_blocks)
 
         with span("apply", self._spans):
-            self.residuals = self.residuals.at[jids].set(new_res)
+            self.residuals = self._scatter_jit(self.residuals, jids, new_res)
             del new_res
             self.params, self.server_state = self._apply_jit(
                 ghat_blocks, self.params, self.server_state, t
@@ -987,31 +1018,37 @@ class CohortEngine:
             if self._collect:
                 jax.block_until_ready(self.params)
         self.round = t + 1
-        out = {
-            k: float(v)
-            for k, v in sinfo.items()
-            if k not in ("participating",)  # recomputed below for parity
-        }
-        if self.cohort.record_nmse:
-            if "true_sum" in payloads:
-                out["nmse"] = float(
-                    self._nmse_true_jit(ghat_blocks, payloads["true_sum"])
-                )
-            else:
-                out["nmse"] = float(
-                    self._nmse_jit(ghat_blocks, payloads["blocks"], rhos_eff)
-                )
+        with span("sync", self._spans):
+            out = {
+                k: float(v)
+                for k, v in sinfo.items()
+                if k not in ("participating",)  # recomputed below for parity
+            }
+            if self.cohort.record_nmse:
+                if "true_sum" in payloads:
+                    out["nmse"] = float(
+                        self._nmse_true_jit(ghat_blocks, payloads["true_sum"])
+                    )
+                else:
+                    out["nmse"] = float(
+                        self._nmse_jit(ghat_blocks, payloads["blocks"], rhos_eff)
+                    )
+            if self._collect and self._sat_jit is not None:
+                out["clip_saturation"] = float(self._sat_jit(payloads["words"]))
         out["cohort"] = len(ids)
         out["participating"] = float(np.sum(w_raw > 0))
         out["arrived"] = float(np.sum(arrived))
         if self._collect:
-            if self._sat_jit is not None:
-                out["clip_saturation"] = float(self._sat_jit(payloads["words"]))
             self._record_round(t, out, stale, ghat_blocks)
         return out
 
     def run(self, rounds: int) -> List[Dict[str, float]]:
         return [self.run_round() for _ in range(rounds)]
+
+
+def _scatter_residuals(residuals, jids, rows):
+    """Writes the cohort's new residual rows back into the registry."""
+    return residuals.at[jids].set(rows)
 
 
 # ---------------------------------------------------------------------------

@@ -253,6 +253,7 @@ def bqcs_encode_fused_pallas(
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
             jax.ShapeDtypeStruct((nb, n), jnp.float32),
         ],
+        name="bqcs_encode_fused_pallas",
         interpret=interpret,
     )(*operands)
     return words, alpha[:, 0], resid

@@ -129,6 +129,7 @@ def gamp_step_pallas(
             jax.ShapeDtypeStruct((nb, m), jnp.float32),
             jax.ShapeDtypeStruct((nb, tl), jnp.float32),
         ],
+        name="gamp_step_pallas",
         interpret=interpret,
     )(ghat, nu_g, shat, theta, y, nu_d, a)
     return outs

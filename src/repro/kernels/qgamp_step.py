@@ -198,6 +198,7 @@ def qgamp_step_pallas(
             jax.ShapeDtypeStruct((nb, m), jnp.float32),
             jax.ShapeDtypeStruct((nb, tl), jnp.float32),
         ],
+        name="qgamp_step_pallas",
         interpret=interpret,
     )(ghat, nu_g, shat, theta, codes, alpha, lo_tau, hi_tau, a)
     return outs

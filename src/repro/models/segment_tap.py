@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.layout import GradientLayout, _leaf_size
+from repro.obs.trace import CLIENT, scoped
 
 __all__ = [
     "Stage",
@@ -472,13 +473,16 @@ class InterleavedSegments:
             ])
 
     def _build_jits(self) -> None:
-        self._ctx_jit = jax.jit(jax.vmap(lambda b: self._ctx_fn(b, self.cfg)))
+        # every stage program runs under the client pass's ``stage`` scope
+        # (obs/trace.py), which leaves the programs' names as they were
+        stage = lambda f: scoped(f, CLIENT, "stage")
+        self._ctx_jit = jax.jit(stage(jax.vmap(lambda b: self._ctx_fn(b, self.cfg))))
         self._fwd_jits, self._bwd_jits = [], []
         for st in self.stages:
             fwd = st.fwd
             if st.has_carry:
                 self._fwd_jits.append(
-                    jax.jit(jax.vmap(fwd, in_axes=(None, 0, 0)))
+                    jax.jit(stage(jax.vmap(fwd, in_axes=(None, 0, 0))))
                 )
 
                 def one(sp, x, ct, c, _fwd=fwd):
@@ -490,13 +494,13 @@ class InterleavedSegments:
                 # place (donating ct too would be unusable -- only one output
                 # matches the shape -- and just warns)
                 self._bwd_jits.append(
-                    jax.jit(jax.vmap(one, in_axes=(None, 0, 0, 0)),
+                    jax.jit(stage(jax.vmap(one, in_axes=(None, 0, 0, 0))),
                             donate_argnums=(1,))
                 )
             else:
                 self._fwd_jits.append(
-                    jax.jit(jax.vmap(lambda sp, c, _fwd=fwd: _fwd(sp, None, c),
-                                     in_axes=(None, 0)))
+                    jax.jit(stage(jax.vmap(lambda sp, c, _fwd=fwd: _fwd(sp, None, c),
+                                           in_axes=(None, 0))))
                 )
 
                 def one0(sp, ct, c, _fwd=fwd):
@@ -507,9 +511,9 @@ class InterleavedSegments:
                 # no donation: the embed gradient (vocab, d) cannot alias the
                 # sequence-shaped cotangent
                 self._bwd_jits.append(
-                    jax.jit(jax.vmap(one0, in_axes=(None, 0, 0)))
+                    jax.jit(stage(jax.vmap(one0, in_axes=(None, 0, 0))))
                 )
-        self._add_jit = jax.jit(jnp.add)
+        self._add_jit = jax.jit(stage(jnp.add))
         self._asm_jits: Dict[int, Any] = {}
 
     def _assemble(self, seg_index: int):
@@ -533,7 +537,7 @@ class InterleavedSegments:
                     )
                 return flat.reshape(flat.shape[0], rows, n)
 
-            jit = self._asm_jits[seg_index] = jax.jit(asm)
+            jit = self._asm_jits[seg_index] = jax.jit(scoped(asm, CLIENT, "stage"))
         return jit
 
     # -- the backward sweep --------------------------------------------------

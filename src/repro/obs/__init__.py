@@ -8,9 +8,10 @@ Three small pieces, composable and individually optional:
     the jitted graphs they build never branch on it at trace time.
   * schema -- the versioned event envelope and the validators the CI smoke
     and the reader share.
-  * trace -- monotonic-clock spans (contextmanager + decorator) with an
-    optional jax.profiler.TraceAnnotation passthrough, so profiler traces
-    and JSONL phase timings share one naming scheme.
+  * trace -- monotonic-clock spans with an optional
+    jax.profiler.TraceAnnotation passthrough, so profiler traces and JSONL
+    phase timings share one naming scheme, a per-round profiler step, and
+    the layer scopes of the round's device programs.
 
 The reader/CLI toolchain lives in reader.py and runs as
 ``python -m repro.obs summarize|tail|compare|validate <run_dir>``.
@@ -27,7 +28,7 @@ from repro.obs.recorder import (
     NullRecorder,
 )
 from repro.obs.schema import SCHEMA_VERSION, validate_event, validate_meta
-from repro.obs.trace import SpanCollector, span, traced
+from repro.obs.trace import SpanCollector, span
 
 __all__ = [
     "MetricsRecorder",
@@ -40,5 +41,4 @@ __all__ = [
     "validate_meta",
     "SpanCollector",
     "span",
-    "traced",
 ]

@@ -1,4 +1,5 @@
-"""Monotonic-clock spans with one naming scheme for JSONL and profiler.
+"""Monotonic-clock spans with one naming scheme for JSONL and profiler,
+and the layer scopes of the round's device programs.
 
 The federated engines wrap each round phase in a span::
 
@@ -10,14 +11,34 @@ The federated engines wrap each round phase in a span::
 Span names are the phase vocabulary shared by the ``phase_ms`` field of
 round events, the ``span`` event kind, and (when enabled) the
 ``jax.profiler.TraceAnnotation`` labels -- a profile and a run log line up
-by construction.  Canonical engine phase names: ``client_pass``,
-``encode``, ``uplink``, ``fold``, ``decode``, ``apply``; plus the
+by construction.  Canonical engine phase names: ``select`` (cohort
+selection, round keys and the per-round prep dispatch), ``uplink``,
+``sync`` (the host waiting on a device value: the channel mask, the
+round's stats), ``client_pass``, ``fold``, ``decode``, ``apply``; plus the
 SUB-phases of the streamed client pass, ``backward`` (time inside the
 gradient producer's next() -- for the interleaved producer, one stage's
 VJP dispatch) and ``encode_overlap`` (the per-segment encode dispatch
 riding on the backward sweep).  Sub-phases nest inside ``client_pass``,
 so aggregations that sum phases must exclude :data:`SUB_PHASES` or the
-nested time double-counts.
+nested time double-counts.  With annotations on, :func:`round_step` marks
+each round as a ``jax.profiler.StepTraceAnnotation`` named
+:data:`ROUND_STEP` (the profiler's step view reads it).
+
+Layer scopes: every jitted program of the cohort round runs its body
+inside one top-level ``jax.named_scope`` naming its layer --
+:data:`UPLINK`, :data:`CLIENT`, :data:`DECODE` or :data:`APPLY` -- with
+sub-scopes below it: ``fedqcs.client/stage`` (the segment producer's stage
+programs), ``fedqcs.client/encode`` (the per-segment encode),
+``fedqcs.decode/dequantize`` (the Bussgang-weighted aggregate of the
+decoded codes) and ``fedqcs.decode/gamp`` (the chunked GAMP solve).  A
+scope is trace-time metadata: it lands in each HLO instruction's
+``op_name`` (``jit(_ps_fn)/fedqcs.decode/gamp/...``; a transform applied
+outside the scope wraps it, as ``vmap(fedqcs.client)/encode/...``, so a
+reader matches the scope anywhere in the path) and changes neither the
+computation nor the program's name.  Eager glue (slices, concatenates, key
+splits) carries no scope, nor do instructions XLA inserts and the loop
+invariants JAX hoists out of a scan's VJP (a reader places those by their
+program).
 
 Overhead: with ``collector=None`` and annotations off, ``span`` is two
 ``time.monotonic()`` calls -- cheap enough to leave in place permanently.
@@ -33,13 +54,17 @@ full device time in the phase that materializes results.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import time
 from contextlib import contextmanager
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
-__all__ = ["SpanCollector", "span", "traced", "ANNOTATE", "SUB_PHASES"]
+__all__ = [
+    "SpanCollector", "span", "round_step", "scoped", "ANNOTATE", "SUB_PHASES",
+    "ROUND_STEP", "UPLINK", "CLIENT", "DECODE", "APPLY",
+]
 
 # Checked once at import: profiler annotations are opt-in by environment.
 ANNOTATE = os.environ.get("REPRO_TRACE_ANNOTATIONS", "") == "1"
@@ -47,6 +72,15 @@ ANNOTATE = os.environ.get("REPRO_TRACE_ANNOTATIONS", "") == "1"
 # Phases that time a slice of another phase (they nest inside client_pass):
 # excluded when summing phase_ms into a round total.
 SUB_PHASES = frozenset({"backward", "encode_overlap"})
+
+# The profiler step that marks one round (annotations on only).
+ROUND_STEP = "fedqcs_round"
+
+# Top-level layer scopes of the round's device programs.
+UPLINK = "fedqcs.uplink"
+CLIENT = "fedqcs.client"
+DECODE = "fedqcs.decode"
+APPLY = "fedqcs.apply"
 
 
 class SpanCollector:
@@ -86,17 +120,27 @@ def span(name: str, collector: Optional[SpanCollector] = None):
             collector.add(name, (time.monotonic() - t0) * 1e3)
 
 
-def traced(name: Optional[str] = None, collector: Optional[SpanCollector] = None):
-    """Decorator form of :func:`span`; name defaults to the function name."""
+def round_step(t: int):
+    """Marks round ``t`` as a profiler step (a no-op unless annotating)."""
+    if not ANNOTATE:
+        return contextlib.nullcontext()
+    from jax.profiler import StepTraceAnnotation
 
-    def wrap(fn):
-        label = name or fn.__name__
+    return StepTraceAnnotation(ROUND_STEP, step_num=t)
 
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with span(label, collector):
-                return fn(*args, **kwargs)
 
-        return inner
+def scoped(fn: Callable, *names: str) -> Callable:
+    """``fn`` with its body inside the nested ``jax.named_scope``s
+    ``names`` (outermost first).  The wrapper keeps ``fn``'s name and
+    signature, so ``jax.jit`` names the program and reads static arguments
+    as it would for ``fn``."""
+    import jax
 
-    return wrap
+    @functools.wraps(fn)
+    def inner(*args, **kwargs):
+        with contextlib.ExitStack() as stack:
+            for name in names:
+                stack.enter_context(jax.named_scope(name))
+            return fn(*args, **kwargs)
+
+    return inner

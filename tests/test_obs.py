@@ -328,7 +328,8 @@ def test_barrier_round_events(barrier_events):
         assert np.isfinite(ev["nmse"])
         assert ev["update_norm"] > 0.0 and ev["param_norm"] > 0.0
         # the barrier phase vocabulary, and round_ms is their sum
-        assert set(ev["phase_ms"]) == {"uplink", "client_pass", "decode", "apply"}
+        assert set(ev["phase_ms"]) == {"select", "uplink", "sync", "client_pass",
+                                       "decode", "apply"}
         assert ev["round_ms"] == pytest.approx(sum(ev["phase_ms"].values()))
         # wire accounting: packed words + one f32 alpha per block, up;
         # an nbar-f32 model broadcast per cohort member, down
@@ -360,7 +361,8 @@ def test_streaming_round_events(stream_events):
         assert 0.0 < ev["gamp_iters_mean"] <= FED.gamp_iters
         assert 0.0 <= ev["clip_saturation"] <= 1.0
         # the streaming phase vocabulary: fold, not decode
-        assert set(ev["phase_ms"]) == {"uplink", "client_pass", "fold", "apply"}
+        assert set(ev["phase_ms"]) == {"select", "uplink", "sync", "client_pass",
+                                       "fold", "apply"}
 
 
 def test_round_stats_unchanged_by_recording(barrier_events):

@@ -6,10 +6,13 @@ scoped VMEM.  These tests compile each kernel of the federated round for one
 chip of a described ``v5e:2x2`` topology -- nothing runs -- at the block
 geometries of the launcher (N=256, and N=255 with the same M) and the
 library default (N=1024), with the tile rows ``kernels.ops`` picks, and check the
-program holds the kernel.
+program holds the kernel as a custom call under the kernel's pinned name (the
+name a profile's kernel events carry; the benchmark's roofline patterns match
+it).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,9 +57,15 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(fn, *args):
+def _assert_kernel(fn, name, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    # pallas_call(name=...) names the custom call's instruction and adds the
+    # name to its op_name, whatever transforms wrap the call
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    assert any(re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", line)
+               and re.search(rf'op_name="[^"]*/{name}/pallas_call"', line)
+               for line in calls), calls
 
 
 @pytest.mark.parametrize(
@@ -75,11 +84,13 @@ def test_bqcs_encode_fused_compiles(one_chip, n, r, q, codebook):
     w = -(-cb.n_codes(m) // per_word)
     tb = ops._encode_tb(4096, n, mp, w)
     nb = 2 * tb
-    fn = lambda g, res, a_t, t: fenc.bqcs_encode_fused_pallas(
+    # vmapped over the cohort, as the engine's per-segment encode calls it
+    fn = jax.vmap(lambda g, res, a_t, t: fenc.bqcs_encode_fused_pallas(
         g, res, a_t, t, s=cfg.s, m=m, bits=cb.bits, vq_d=cb.dim, tb=tb
-    )
-    _assert_kernel(fn, _spec(one_chip, (nb, n)), _spec(one_chip, (nb, n)),
-                   _spec(one_chip, (n, mp)), _spec(one_chip, tab))
+    ), in_axes=(0, 0, None, None))
+    _assert_kernel(fn, "bqcs_encode_fused_pallas", _spec(one_chip, (2, nb, n)),
+                   _spec(one_chip, (2, nb, n)), _spec(one_chip, (n, mp)),
+                   _spec(one_chip, tab))
 
 
 @pytest.mark.parametrize("n,r,q", GEOMETRIES)
@@ -89,8 +100,8 @@ def test_gamp_step_compiles(one_chip, n, r, q):
     nb = 2 * tb
     fn = lambda *xs: gstep.gamp_step_pallas(*xs, n_components=L, tb=tb)
     s = lambda *shape: _spec(one_chip, shape)
-    _assert_kernel(fn, s(nb, n), s(nb, n), s(nb, m), s(nb, TL), s(nb, m),
-                   s(nb, 1), s(m, n))
+    _assert_kernel(fn, "gamp_step_pallas", s(nb, n), s(nb, n), s(nb, m), s(nb, TL),
+                   s(nb, m), s(nb, 1), s(m, n))
 
 
 @pytest.mark.parametrize("n,r,q", GEOMETRIES)
@@ -101,6 +112,6 @@ def test_qgamp_step_packed_compiles(one_chip, n, r, q):
     nb = 2 * tb
     fn = lambda *xs: qstep.qgamp_step_pallas(*xs, n_components=L, tb=tb, bits=q)
     s = lambda *shape: _spec(one_chip, shape)
-    _assert_kernel(fn, s(nb, n), s(nb, n), s(nb, m), s(nb, TL),
+    _assert_kernel(fn, "qgamp_step_pallas", s(nb, n), s(nb, n), s(nb, m), s(nb, TL),
                    _spec(one_chip, (nb, w), jnp.uint32), s(nb, 1),
                    s(2**q), s(2**q), s(m, n))
