@@ -58,6 +58,7 @@ from repro.core.quantizer import (
     LloydMaxQuantizer,
     _phi,
     design_lloyd_max,
+    select_levels,
 )
 
 __all__ = [
@@ -150,7 +151,8 @@ class ScalarCodebook(Codebook):
 
     Encode: ``searchsorted(thresholds, y + dither)`` -- identical to the
     pre-refactor quantizer.encode when dither is None.
-    Decode: ``levels[code] - dither``.
+    Decode: ``levels[code] - dither``, the lookup through
+    ``quantizer.select_levels`` (a select chain, not a table gather).
     """
 
     levels: np.ndarray = None  # (L,) ascending reconstruction points
@@ -175,7 +177,7 @@ class ScalarCodebook(Codebook):
         return jnp.searchsorted(taus, y, side="left").astype(jnp.uint8)
 
     def decode(self, codes: jnp.ndarray, m: Optional[int] = None, dtype=jnp.float32):
-        deq = self.jnp_levels(dtype)[codes.astype(jnp.int32)]
+        deq = select_levels(self.jnp_levels(dtype), codes)
         if self.dither is not None:
             deq = deq - self.jnp_dither(dtype)
         return deq if m is None else deq[..., :m]

@@ -34,6 +34,7 @@ import numpy as np
 from repro.core import sensing, sparsify
 from repro.core.codebook import Codebook, index_bits, make_codebook
 from repro.core.layout import GradientLayout
+from repro.core.quantizer import select_levels
 
 __all__ = [
     "FedQCSConfig",
@@ -286,11 +287,10 @@ def decode_packed(
     words: jnp.ndarray, bits: int, m: int, levels: jnp.ndarray
 ) -> jnp.ndarray:
     """Dequantize straight from the packed wire words: (..., W) uint32 ->
-    (..., m) f32 reconstruction levels.  The level lookup indexes the
-    shift/masked lane groups directly, so the (..., M) uint8 index view is
-    never materialized (the shifted int temporaries fuse into the gather)."""
-    idx = _unpack_groups(words, bits).astype(jnp.int32)
-    deq = levels[idx]  # (..., per_word, W)
+    (..., m) f32 reconstruction levels.  The level lookup
+    (:func:`~repro.core.quantizer.select_levels`) reads the shift/masked
+    lane groups directly, so no (..., M) uint8 index view is built."""
+    deq = select_levels(levels, _unpack_groups(words, bits))  # (..., per_word, W)
     return deq.reshape(words.shape[:-1] + (-1,))[..., :m]
 
 

@@ -27,6 +27,7 @@ __all__ = [
     "encode",
     "decode",
     "quantize",
+    "select_levels",
 ]
 
 _SQRT2 = math.sqrt(2.0)
@@ -157,10 +158,23 @@ def encode(x: jnp.ndarray, quantizer: LloydMaxQuantizer) -> jnp.ndarray:
     return jnp.searchsorted(taus, x, side="left").astype(jnp.uint8)
 
 
+def select_levels(levels: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``levels[idx]`` as an elementwise select chain over the static level
+    count L: exact for every idx in [0, L), and an index at or above L reads
+    ``levels[L-1]`` (the clamp of JAX's default gather).  On a TPU a table
+    gather lowers to a materialized index array and a gathered copy; the
+    chain is one loop fusion with whatever consumes it (DESIGN.md
+    #Codebooks)."""
+    n = levels.shape[0]
+    out = jnp.broadcast_to(levels[n - 1], idx.shape)
+    for l in range(n - 2, -1, -1):
+        out = jnp.where(idx == l, levels[l], out)
+    return out
+
+
 def decode(codes: jnp.ndarray, quantizer: LloydMaxQuantizer, dtype=jnp.float32) -> jnp.ndarray:
     """Maps code indices back to reconstruction levels q_i."""
-    levels = quantizer.jnp_levels(dtype)
-    return levels[codes.astype(jnp.int32)]
+    return select_levels(quantizer.jnp_levels(dtype), codes)
 
 
 def quantize(x: jnp.ndarray, quantizer: LloydMaxQuantizer) -> jnp.ndarray:

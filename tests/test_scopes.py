@@ -158,8 +158,11 @@ def test_decode_sub_scopes(round_programs):
     gathers = [re.search(r'op_name="([^"]*)"', line).group(1)
                for line in text.splitlines() if " gather(" in line]
     if method == "fedqcs-ae":
-        # levels[codes]: the Bussgang aggregate's dequantize
-        assert any(_in_scope(n, "fedqcs.decode/dequantize") for n in gathers)
+        # levels[codes], the Bussgang aggregate's dequantize: a select chain
+        # over the levels, not a table gather
+        dequantize = [n for n in names if _in_scope(n, "fedqcs.decode/dequantize")]
+        assert any(n.endswith("/select_n") for n in dequantize), dequantize
+        assert not any(_in_scope(n, "fedqcs.decode/dequantize") for n in gathers)
     else:  # EA dequantizes inside the per-client solve
         assert not any("/dequantize/" in n for n in names)
 

@@ -8,7 +8,7 @@ geometries of the launcher (N=256, and N=255 with the same M) and the
 library default (N=1024), with the tile rows ``kernels.ops`` picks, and check the
 program holds the kernel as a custom call under the kernel's pinned name (the
 name a profile's kernel events carry; the benchmark's roofline patterns match
-it).
+it).  The scalar dequantize is compiled the same way and must hold no gather.
 """
 
 import os
@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.compression import FedQCSConfig, packed_width
+from repro.core import bussgang
+from repro.core.compression import FedQCSConfig, decode_packed, packed_width
 from repro.core.codebook import make_codebook
 from repro.kernels import bqcs_encode_fused as fenc
 from repro.kernels import gamp_step as gstep
@@ -115,3 +116,37 @@ def test_qgamp_step_packed_compiles(one_chip, n, r, q):
     _assert_kernel(fn, "qgamp_step_pallas", s(nb, n), s(nb, n), s(nb, m), s(nb, TL),
                    _spec(one_chip, (nb, w), jnp.uint32), s(nb, 1),
                    s(2**q), s(2**q), s(m, n))
+
+
+# The scalar dequantize is a select chain over the static levels
+# (quantizer.select_levels): a table gather on the chip materializes a
+# clamped index array and a gathered copy, 2.9 s of estimated time at the
+# launcher's 853,544 rows against 1.5 ms for the select.  The compiled
+# program must hold no gather, whichever entry point reads the levels.
+DEQUANTIZE_ROWS = 4096
+
+
+def _assert_no_gather(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert " gather(" not in text, [l for l in text.splitlines() if " gather(" in l]
+
+
+@pytest.mark.parametrize("q", [3, 8])
+@pytest.mark.parametrize("entry", ["aggregate_codes", "aggregate_packed", "decode_packed"])
+def test_scalar_dequantize_compiles_without_gather(one_chip, entry, q):
+    cfg = FedQCSConfig(block_size=256, reduction_ratio=3, bits=q)
+    cb = make_codebook(cfg)
+    m, k, nb = cfg.m, 2, DEQUANTIZE_ROWS
+    w = packed_width(m, q)
+    s = lambda *shape: _spec(one_chip, shape)
+    alphas_rhos = (s(k, nb), s(k))
+    if entry == "aggregate_codes":
+        fn = lambda c, a, r: bussgang.aggregate_codes(c, a, r, cb)
+        args = (_spec(one_chip, (k, nb, m), jnp.uint8),) + alphas_rhos
+    elif entry == "aggregate_packed":
+        fn = lambda wd, a, r: bussgang.aggregate_packed(wd, a, r, cb, m)
+        args = (_spec(one_chip, (k, nb, w), jnp.uint32),) + alphas_rhos
+    else:
+        fn = lambda wd: decode_packed(wd, q, m, cb.jnp_levels())
+        args = (_spec(one_chip, (k, nb, w), jnp.uint32),)
+    _assert_no_gather(fn, *args)
